@@ -37,6 +37,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.detection import scenes as sc
+from repro.launch.compile_cache import enable_compile_cache
 
 ART = "artifacts/bench"
 
@@ -975,6 +976,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     for name in names:
         t0 = time.time()

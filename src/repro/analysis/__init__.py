@@ -2,7 +2,7 @@
 
 PRs 1-5 concentrated the ECORE reproduction into a few load-bearing
 invariants — a pure scanned closed loop, one serving dispatch plane,
-bit-exact jnp oracles per kernel, and a pinned jax 0.4.37 environment.
+bit-exact jnp oracles per kernel, and one call site per environment API.
 This package turns those prose rules into enforced ones:
 
 * family ECO1xx — scan/jit purity (host syncs, impure calls, mutation)
@@ -10,7 +10,7 @@ This package turns those prose rules into enforced ones:
   serving loops)
 * family ECO3xx — serving thread/async safety
 * family ECO4xx — kernel oracle contract (ops.py + ref.py + parity test)
-* family ECO5xx — environment pins (AxisType / make_mesh / hypothesis)
+* family ECO5xx — one call site each for AxisType / make_mesh / hypothesis
 
 CLI: ``python -m repro.analysis [paths] [--format text|json]``.
 Suppress one finding with ``# repro-lint: disable=<rule>`` (justification

@@ -69,7 +69,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         description="repro architectural lint: scan/jit purity (ECO1xx), "
                     "hot-path discipline (ECO2xx), serving thread safety "
                     "(ECO3xx), kernel oracle contract (ECO4xx), "
-                    "environment pins (ECO5xx); with --project also "
+                    "one call site per environment API (ECO5xx); with "
+                    "--project also "
                     "concurrency (ECO6xx), protocol contracts (ECO7xx), "
                     "transitive purity (ECO12x) and the suppression audit "
                     "(ECO900).")

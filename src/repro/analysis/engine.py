@@ -111,11 +111,14 @@ class SourceFile:
         self.text = text
         self.lines = text.splitlines()
         self.tree = ast.parse(text)  # caller converts SyntaxError to E001
+        #: every node of the tree in ``ast.walk`` order, walked once for
+        #: all the rules that scan the whole file
+        self.nodes = list(ast.walk(self.tree))
         #: decorator-stack start line -> decorated def/class line, so a
         #: standalone marker above ``@decorator`` also covers the def line
         #: (rules report at the def, not the decorator).
         dec_spans = {}
-        for node in ast.walk(self.tree):
+        for node in self.nodes:
             decs = getattr(node, "decorator_list", None)
             if decs:
                 first = min(d.lineno for d in decs)

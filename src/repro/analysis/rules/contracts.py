@@ -84,7 +84,7 @@ class BackendConformance(_ContractRule):
             mod = proj.modules.get(module_name(src.path))
             if mod is None:
                 continue
-            for node in ast.walk(src.tree):
+            for node in src.nodes:
                 if not isinstance(node, ast.Call):
                     continue
                 fname = (dotted_name(node.func) or "").rsplit(".", 1)[-1]

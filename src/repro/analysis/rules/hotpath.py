@@ -32,7 +32,7 @@ class HotPathLoop(Rule):
         self.hot = tuple(options.get("hot-functions") or ())
 
     def check(self, src):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if not (isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef))
                     and node.name in self.hot):
                 continue
@@ -62,7 +62,7 @@ class HotProfileMutation(Rule):
     _CALLS = frozenset({"observe", "observe_pair", "load_state"})
 
     def check(self, src):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)):
                 if node.func.attr in self._CALLS:
@@ -110,7 +110,7 @@ class ForkedServingLoop(Rule):
         self.exclude = tuple(ForkedServingLoop.exclude) + plane
 
     def check(self, src):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
                     and node.func.attr == "serve_batch"):

@@ -156,7 +156,7 @@ class KernelSilentFallback(Rule):
     include = ("*/kernels/*/ops.py",)
 
     def check(self, src: SourceFile):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if not isinstance(node, ast.If) or not _shape_guard(node.test):
                 continue
             if _falls_back_to_oracle(node.body):

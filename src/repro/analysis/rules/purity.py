@@ -152,7 +152,7 @@ class LoopHostScalarize(Rule):
 
     def check(self, src):
         annotate_parents(src.tree)
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Name)
                     and node.func.id in ("int", "float", "bool")

@@ -32,7 +32,7 @@ class BlockingUnderLock(Rule):
     include = ("*/repro/serving/*.py",)
 
     def check(self, src):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if not isinstance(node, ast.With):
                 continue
             if not any(self._lockish(item.context_expr)
@@ -88,7 +88,7 @@ class CrossThreadFutureCompletion(Rule):
         annotate_parents(src.tree)
         afut_names = set()
         scheduled_fns = set()
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             value = getattr(node, "value", None)
             if (isinstance(node, (ast.Assign, ast.AnnAssign))
                     and isinstance(value, ast.Call)
@@ -107,7 +107,7 @@ class CrossThreadFutureCompletion(Rule):
                         scheduled_fns.add(arg.id)
         if not afut_names:
             return
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
                     and node.func.attr in ("set_result", "set_exception")
@@ -134,7 +134,7 @@ class BlindExcept(Rule):
     include = ("*/repro/serving/*.py",)
 
     def check(self, src):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if not isinstance(node, ast.ExceptHandler):
                 continue
             if node.type is None:
@@ -168,7 +168,7 @@ class WallClockRetry(Rule):
     include = ("*/repro/serving/*.py", "*/repro/traffic/*.py")
 
     def check(self, src):
-        for node in ast.walk(src.tree):
+        for node in src.nodes:
             if isinstance(node, ast.Call) and self._is_sleep(node):
                 yield self.hit(node, src.path,
                                "wall-clock sleep in the serving plane — "

@@ -135,8 +135,6 @@ def run_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
     t2 = time.time()
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # jax <= 0.4.x wraps the dict in a list
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     if save_hlo:

@@ -12,16 +12,14 @@ import jax
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` across JAX versions: ``jax.sharding.AxisType`` only
-    exists from 0.5.x on; older releases (0.4.37 in this container) default to
-    auto axis types, so the kwarg is passed only when available."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        # repro-lint: disable=ECO502 -- THE sanctioned call site: this
-        # wrapper is the version gate every other module must go through
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))  # repro-lint: disable=ECO502
+    """``jax.make_mesh`` with every axis of type Auto: the one call site of
+    ``jax.make_mesh`` and ``jax.sharding.AxisType`` (lint ECO501/502), so
+    every mesh in the repo is built the same way."""
+    # repro-lint: disable=ECO501 -- THE call site every other module uses
+    auto = jax.sharding.AxisType.Auto
+    # repro-lint: disable=ECO502 -- THE call site every other module uses
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

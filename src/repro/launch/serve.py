@@ -6,10 +6,12 @@
   PYTHONPATH=src python -m repro.launch.serve --rate 20 --duration 5 \
       --pattern flash --pods 2 --max-wait-ms 25   # open-loop SLO replay
 
-On this CPU container backends are REDUCED variants of the assigned archs
-(real prefill+decode runs, batched); the routing profile comes from the
-production dry-run roofline (artifacts/dryrun.jsonl) when available, so the
-router makes the same decisions it would on the pod.
+Backends are always REDUCED variants of the assigned archs (d_model <= 128,
+one block; real prefill+decode runs, batched), on a CPU and on a TPU alike;
+``chip_smoke.py`` is what serves qwen2.5-3b at its published width.  The
+routing profile comes from the production dry-run roofline
+(artifacts/dryrun.jsonl) when available, so the router makes the decisions
+it would make over full-size backends.
 
 The driver is a thin loop over ``EcoreService``: it builds a ``PoolPolicy``
 (Algorithm 1 over prompt-length buckets), submits ``RouteRequest``s, and
@@ -50,6 +52,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.policy import Observation, PoolPolicy, RouteRequest
 from repro.core.profiles import ProfileEntry, ProfileTable
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import Backend
 from repro.serving.pool import (LENGTH_BUCKETS, ServingPool,
                                 capability_score, pool_table_from_dryrun)
@@ -200,6 +203,7 @@ def main(argv=None):
                               "accounting (needs --rate)")
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.pods < 1:
         ap.error(f"--pods {args.pods}: need at least one pod")
     if args.use_async and args.pods != 1:

@@ -29,6 +29,12 @@ lock), so ``submit_batch`` fans each pod's shard out on a small thread
 pool: pods serve concurrently — XLA releases the GIL during backend
 execution — which is where the multi-pod throughput scaling comes from
 (``benchmarks/run.py --only cluster``).
+
+Pods map to devices: pod ``i`` builds its backends with
+``jax.devices()[i % jax.device_count()]`` as the default device, so on a
+four-chip host four pods put their models on four chips (an
+``engine.Backend`` commits its weights to that device).  With one device
+every pod shares it, as before.
 """
 from __future__ import annotations
 
@@ -263,8 +269,19 @@ class EcoreCluster:
         self._closed = False
 
     def _make_pod(self, index: int) -> EcoreService:
+        import jax  # lazy, like the shard kernels
+
+        device = jax.devices()[index % jax.device_count()]
+        factory = self._backend_factory
+
+        def pod_factory(decision: RouteDecision):
+            # the pod's backends are built with its device as the default,
+            # so an engine.Backend commits its weights there
+            with jax.default_device(device):
+                return factory(decision)
+
         return EcoreService(self._policy_factory(index),
-                            self._backend_factory,
+                            pod_factory,
                             max_wait_ms=self._max_wait_ms,
                             clock=self._clock,
                             retain_results=self._retain,

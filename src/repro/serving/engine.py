@@ -2,9 +2,12 @@
 
 A ``Backend`` wraps (config, params, jitted prefill/decode) and serves
 batches of requests; the pool layer (pool.py) profiles backends and lets the
-ECORE gateway route requests among them.  On this CPU container backends run
-reduced configs on the host mesh; on a TPU pod the same code runs the full
-configs under the production mesh.
+ECORE gateway route requests among them.  A backend runs on ONE device: its
+weights are committed to the device that is the default when it is built
+(``jax.default_device``; the first device otherwise), and its steps run
+there.  Tests and ``launch/serve.py`` build reduced configs; ``chip_smoke.py``
+serves qwen2.5-3b at its published width on one TPU chip.  No serving path
+shards a model over a mesh.
 """
 from __future__ import annotations
 
@@ -55,8 +58,12 @@ class Backend:
                  max_batch: int = 8, max_seq: int = 256, seed: int = 0):
         self.name = name
         self.cfg = cfg
-        self.params = params if params is not None else init_params(
-            cfg, jax.random.PRNGKey(seed))
+        if params is None:
+            params = init_params(cfg, jax.random.PRNGKey(seed))
+        # committed, so the jitted steps run on this device whichever
+        # thread serves the batch
+        self.params = jax.device_put(
+            params, jax.config.jax_default_device or jax.devices()[0])
         self.max_batch = max_batch
         self.max_seq = max_seq
         self._prefill = jax.jit(

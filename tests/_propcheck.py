@@ -1,101 +1,4 @@
-"""Property-testing compat layer: real hypothesis when installed, otherwise
-a tiny deterministic example-based substitute.
-
-The fallback draws ``max_examples`` pseudo-random examples from a fixed seed
-(plus boundary values for scalar strategies), so the property tests still
-exercise many inputs on containers without ``hypothesis`` — with reproducible
-failures — while dev machines with the real package keep full shrinking.
-
-Only the strategy subset this suite uses is implemented: ``integers``,
-``floats``, ``sampled_from``, ``tuples``, ``lists``.
-"""
-from __future__ import annotations
-
-try:
-    # the ONE sanctioned hypothesis import: this module IS the compat shim
-    from hypothesis import given, settings  # noqa: F401  # repro-lint: disable=ECO503
-    import hypothesis.strategies as st      # noqa: F401  # repro-lint: disable=ECO503
-except ImportError:
-    import functools
-    import inspect
-    import random
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    class _Strategies:
-        @staticmethod
-        def integers(min_value, max_value):
-            bounds = (min_value, max_value)
-
-            def draw(rng):
-                u = rng.random()
-                if u < 0.08:
-                    return bounds[rng.random() < 0.5]
-                return rng.randint(min_value, max_value)
-            return _Strategy(draw)
-
-        @staticmethod
-        def floats(min_value, max_value, allow_nan=False):
-            bounds = (float(min_value), float(max_value))
-
-            def draw(rng):
-                u = rng.random()
-                if u < 0.08:
-                    return bounds[rng.random() < 0.5]
-                return rng.uniform(*bounds)
-            return _Strategy(draw)
-
-        @staticmethod
-        def sampled_from(elements):
-            elements = list(elements)
-            return _Strategy(lambda rng: rng.choice(elements))
-
-        @staticmethod
-        def tuples(*strategies):
-            return _Strategy(
-                lambda rng: tuple(s.draw(rng) for s in strategies))
-
-        @staticmethod
-        def lists(elements, min_size=0, max_size=10, unique_by=None):
-            def draw(rng):
-                size = rng.randint(min_size, max_size)
-                out, seen = [], set()
-                for _ in range(50 * max(size, 1)):
-                    if len(out) >= size:
-                        break
-                    x = elements.draw(rng)
-                    if unique_by is not None:
-                        key = unique_by(x)
-                        if key in seen:
-                            continue
-                        seen.add(key)
-                    out.append(x)
-                assert len(out) >= min_size, "strategy cannot fill min_size"
-                return out
-            return _Strategy(draw)
-
-    st = _Strategies()
-
-    def given(*arg_strategies, **kw_strategies):
-        def decorate(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                rng = random.Random(0)
-                for _ in range(getattr(wrapper, "_pc_max_examples", 25)):
-                    drawn_args = tuple(s.draw(rng) for s in arg_strategies)
-                    drawn_kw = {k: s.draw(rng)
-                                for k, s in kw_strategies.items()}
-                    fn(*args, *drawn_args, **kwargs, **drawn_kw)
-            # strategy-fed params must not look like pytest fixtures
-            del wrapper.__wrapped__
-            wrapper.__signature__ = inspect.Signature()
-            return wrapper
-        return decorate
-
-    def settings(max_examples=25, **_ignored):
-        def decorate(fn):
-            fn._pc_max_examples = max_examples
-            return fn
-        return decorate
+"""The one import site of hypothesis (lint ECO503): property tests import
+``given``, ``settings`` and ``st`` from here."""
+from hypothesis import given, settings  # noqa: F401  # repro-lint: disable=ECO503
+import hypothesis.strategies as st      # noqa: F401  # repro-lint: disable=ECO503

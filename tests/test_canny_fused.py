@@ -1,7 +1,8 @@
 """Fused Canny megakernel: bit-exact parity with the jnp oracle.
 
 All Pallas runs use interpret mode (CPU) — marked ``pallas`` so a TPU CI
-lane can select them; they stay in tier-1 (fast, not ``slow``).  The 2D
+lane can select them; they stay in tier-1 (fast, not ``slow``).  What the
+TPU compiler accepts is checked in tests/test_tpu_compile.py.  The 2D
 lane-tiled grid means there is no width limit any more: the cases below
 cover lane tiling, the column halo, frames narrower than one lane tile,
 widths straddling the tile boundary, a >4096-wide frame (the old
@@ -66,6 +67,15 @@ def test_4k_frame_bit_identical():
     np.testing.assert_array_equal(got, np.asarray(ref.canny_edge(img)))
 
 
+def test_1080p_frame_bit_identical_at_picked_tiles():
+    """The tiles pick_tiles gives 1080p (one 1920-lane tile, 128 rows, a
+    ragged last row tile) match the oracle exactly."""
+    assert pick_tiles(1080, 1920) == (128, 1920)
+    img = _rand((1, 1080, 1920), seed=8)
+    got = np.asarray(canny_edge_pallas(img, interpret=True))
+    np.testing.assert_array_equal(got, np.asarray(ref.canny_edge(img)))
+
+
 def test_fused_thresholds_forwarded():
     img = _rand((1, 48, 48), seed=7)
     got = np.asarray(canny_edge_pallas(img, lo=0.2, hi=0.5, tile_rows=16,
@@ -85,6 +95,17 @@ def test_tile_smaller_than_halo_is_an_error():
     with pytest.raises(ValueError, match="HALO"):
         canny_edge_pallas(_rand((1, 32, 32)), tile_lanes=HALO - 1,
                           interpret=True)
+
+
+def test_tile_the_tpu_cannot_take_fails_fast():
+    """Outside interpret mode a tile off the (16, 128) grid raises instead
+    of being rounded; interpret mode takes it."""
+    img = _rand((1, 48, 64))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        canny_edge_pallas(img, tile_lanes=64)
+    with pytest.raises(ValueError, match="multiple of 16"):
+        canny_edge_pallas(img, tile_rows=24, tile_lanes=128)
+    canny_edge_pallas(img, tile_lanes=64, interpret=True)
 
 
 def test_pick_tiles_respects_vmem_budget():

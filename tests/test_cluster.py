@@ -123,6 +123,28 @@ def test_cluster_serves_across_pods_and_aggregates_stats():
     assert len(built) == 2
 
 
+def test_cluster_pods_build_backends_on_their_device():
+    """Pod i builds its backends with jax.devices()[i % device_count] as
+    the default device; with one device that is every pod's (unchanged
+    single-device behaviour)."""
+    import jax
+
+    seen = []
+
+    def factory(decision):
+        seen.append(jax.config.jax_default_device)
+        return _StubBackend(decision.backend, max_batch=2)
+
+    with EcoreCluster(lambda i: PoolPolicy(_pool()), factory,
+                      pods=3) as cluster:
+        cluster.submit_batch([_req(i) for i in range(6)])
+        cluster.drain()
+    n = jax.device_count()
+    assert sorted(d.id for d in seen) == sorted(
+        jax.devices()[i % n].id for i in range(3))
+    assert jax.config.jax_default_device is None   # nothing leaks out
+
+
 def test_cluster_scalar_submit_matches_batch_sharding():
     """Under rendezvous (assignment depends only on the uid, not on live
     depths) the per-request path (scalar reference) and the batch path

@@ -58,3 +58,19 @@ def test_backend_stateful_families():
     res = be.serve_batch([Request(uid=0, prompt=np.arange(7),
                                   max_new_tokens=4)])[0]
     assert res.tokens.shape == (4,)
+
+
+def test_backend_commits_weights_to_the_default_device():
+    """A backend commits its weights to the device that is the default
+    when it is built, so its steps run there from any thread."""
+    import jax
+
+    cfg = get_config("qwen2.5-3b").reduced()
+    dev = jax.devices()[-1]
+    with jax.default_device(dev):
+        be = Backend("qwen", cfg, max_seq=32)
+    leaves = jax.tree.leaves(be.params)
+    assert all(x.committed and x.devices() == {dev} for x in leaves)
+    res = be.serve_batch([Request(uid=0, prompt=np.arange(4),
+                                  max_new_tokens=2)])[0]
+    assert res.tokens.shape == (2,)

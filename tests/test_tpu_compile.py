@@ -1,0 +1,97 @@
+"""Compile the served path's TPU programs for a described TPU v5e chip.
+
+Nothing runs: the TPU compiler, installed with JAX, compiles for a v5e that
+is described, not attached, and refuses what the chip would refuse (tiles
+that break the (8, 128) layout, ops with no Mosaic lowering, too much VMEM,
+a program that does not fit HBM).  Interpret-mode parity
+(tests/test_canny_fused.py) cannot see any of that.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and the test workers
+must all collect the same tests.  Where it cannot be described the tests
+skip.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.canny_fused.canny_fused import (
+    VMEM_BUDGET_BYTES, canny_edge_pallas, pick_tiles, tile_bytes)
+from repro.models import decode_step, init_params, prefill
+
+#: v5e HBM per chip, rounded down
+HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+    # a described chip's compiles cannot be read back from the persistent
+    # cache, so keep them out of it
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    mp.undo()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("b,h,w", [(8, 96, 96), (4, 1088, 1920),
+                                   (1, 2160, 3840)])
+def test_canny_megakernel_compiles_at_picked_tiles(one_chip, b, h, w):
+    """The picked tiles fit the VMEM model's budget and the compiler takes
+    them, ragged ``dims`` included."""
+    assert tile_bytes(*pick_tiles(h, w)) <= VMEM_BUDGET_BYTES
+    img = jax.ShapeDtypeStruct((b, h, w), jnp.float32, sharding=one_chip)
+    dims = jax.ShapeDtypeStruct((b, 2), jnp.int32, sharding=one_chip)
+    compiled = canny_edge_pallas.lower(img, dims).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _fits_hbm(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 1e9:.2f} GB > {HBM_BYTES / 1e9} GB"
+    return m
+
+
+@pytest.fixture(scope="module")
+def qwen(one_chip):
+    """qwen2.5-3b at its published width and depth in bf16, as shapes."""
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"),
+                              param_dtype="bfloat16")
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    return cfg, params
+
+
+def test_qwen_prefill_and_decode_compile_and_fit_one_chip(one_chip, qwen):
+    """The serving steps chip_smoke.py runs (batch 4, 512-token prompts,
+    a 1024-token cache) compile for one v5e and fit its HBM."""
+    cfg, params = qwen
+    b, s, max_seq = 4, 512, 1024
+    tokens = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, t: prefill(p, cfg, t, None, max_seq=max_seq))
+    m = _fits_hbm(step.lower(params, tokens).compile())
+    assert m.argument_size_in_bytes > 6e9     # all 36 layers, in bf16
+    _, cache = jax.eval_shape(step, params, tokens)
+    token = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+    decode = jax.jit(lambda p, t, c: decode_step(p, cfg, t, c))
+    _fits_hbm(decode.lower(params, token, _on(one_chip, cache)).compile())
