@@ -413,17 +413,11 @@ def bench_kernels(quick=False):
 
 def bench_serve(quick=False):
     """End-to-end EcoreService throughput: requests/s through route ->
-    dispatch -> batched serve on real (reduced) backends, flush counts, and
-    the p50/p95 queue wait a request pays for batching under the threaded
-    deadline-bounded flusher.  Appended to BENCH_gateway.json.
-
-    Two separated latency planes (the service accounts them apart): queue
-    wait is submit -> flush TRIGGERED (deadline expiry / full batch —
-    bounded by max_wait_ms under a healthy flusher), service time is
-    trigger -> completion (waiting behind other flushes under the service
-    lock + the serve itself).  On this CPU container service time is
-    dominated by first-batch jit compiles and collapses on a TPU pod;
-    queue wait genuinely tracks the deadline."""
+    dispatch -> batched serve on real (reduced) backends, and flush counts
+    under the threaded deadline-bounded flusher.  Appended to
+    BENCH_gateway.json.  Where a request waited and why shows in the
+    ``ecore.flush`` spans of a profiler trace (``trigger``,
+    ``waited_ms``)."""
     from repro.configs import get_config
     from repro.core.policy import PoolPolicy, RouteRequest
     from repro.launch.serve import PROMPT_CAP, synthetic_pool_table
@@ -462,13 +456,6 @@ def bench_serve(quick=False):
         service.close()
     assert len(served) == n
 
-    def pcts(xs):
-        xs = sorted(xs)
-        return (xs[len(xs) // 2],
-                xs[min(int(len(xs) * 0.95), len(xs) - 1)])
-
-    wait_p50, wait_p95 = pcts(stats["queue_wait_ms"])
-    svc_p50, svc_p95 = pcts(stats["service_ms"])
     row = {"serve": {
         "requests": n,
         "backends": stats["backends"],
@@ -476,10 +463,6 @@ def bench_serve(quick=False):
         "serve_calls": stats["serve_calls"],
         "deadline_flushes": stats["deadline_flushes"],
         "max_wait_ms": max_wait_ms,
-        "queue_wait_p50_ms": wait_p50,
-        "queue_wait_p95_ms": wait_p95,
-        "service_p50_ms": svc_p50,
-        "service_p95_ms": svc_p95,
     }}
     print("\n== serve (EcoreService end-to-end) ==")
     print("metric,value")

@@ -8,6 +8,13 @@ weights are committed to the device that is the default when it is built
 there.  Tests and ``launch/serve.py`` build reduced configs; ``chip_smoke.py``
 serves qwen2.5-3b at its published width on one TPU chip.  No serving path
 shards a model over a mesh.
+
+The host loop is traced with ``jax.profiler.TraceAnnotation`` spans named
+``ecore.*`` (``ecore.serve_batch`` and, inside it, ``ecore.pad``,
+``ecore.prefill``, one ``ecore.decode_step`` per token after the first,
+``ecore.decode_wait`` and ``ecore.gather``; ``ecore.flush`` around a
+queue's flush).  They cost under a microsecond each when no profiler runs,
+and land on the device trace's clock when one does.
 """
 from __future__ import annotations
 
@@ -18,6 +25,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import ModelConfig, decode_step, init_params, prefill
 from repro.data.tokens import modality_inputs
@@ -66,10 +74,23 @@ class Backend:
             params, jax.config.jax_default_device or jax.devices()[0])
         self.max_batch = max_batch
         self.max_seq = max_seq
-        self._prefill = jax.jit(
-            lambda p, t, pe: prefill(p, cfg, t, pe, max_seq=max_seq))
-        self._decode = jax.jit(lambda p, t, c: decode_step(p, cfg, t, c))
+
+        # named, so the device trace's program names say prefill or decode
+        def prefill_step(p, t, pe):
+            return prefill(p, cfg, t, pe, max_seq=max_seq)
+
+        def decode_token(p, t, c):
+            return decode_step(p, cfg, t, c)
+
+        self._prefill = jax.jit(prefill_step)
+        self._decode = jax.jit(decode_token)
         self._rng = np.random.default_rng(seed)
+        #: batch x its largest max_new_tokens, summed over serve_batch calls
+        #: (the decode slots run), and each request's own max_new_tokens
+        #: summed (the tokens asked for); their ratio is the share of
+        #: decoded slots some request wanted
+        self.decode_slots = 0
+        self.tokens_asked = 0
 
     def serve_batch(self, requests: List[Request]) -> List[Result]:
         """Greedy-decode a batch of requests (piggybacked, like the paper's
@@ -83,31 +104,46 @@ class Backend:
         assert requests
         b = len(requests)
         max_prompt = max(len(r.prompt) for r in requests)
-        tokens = np.zeros((b, max_prompt), np.int32)
-        for i, r in enumerate(requests):  # left-pad-free simple right align
-            tokens[i, :len(r.prompt)] = r.prompt % self.cfg.vocab_size
-        extra = modality_inputs(self.cfg, b, self._rng)
-        pe = extra.get("prefix_embeds")
-
-        t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, jnp.asarray(tokens), pe)
-        next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        jax.block_until_ready(next_tok)
-        t1 = time.perf_counter()
-
         max_new = max(r.max_new_tokens for r in requests)
-        out = [next_tok]
-        for _ in range(max_new - 1):
-            logits, cache = self._decode(self.params, next_tok, cache)
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(next_tok)
-        jax.block_until_ready(next_tok)
-        t2 = time.perf_counter()
+        asked = sum(r.max_new_tokens for r in requests)
+        with TraceAnnotation("ecore.serve_batch", batch=b,
+                             prompt_len=max_prompt, max_new=max_new,
+                             tokens_asked=asked):
+            with TraceAnnotation("ecore.pad"):
+                tokens = np.zeros((b, max_prompt), np.int32)
+                for i, r in enumerate(requests):  # right-padded
+                    tokens[i, :len(r.prompt)] = r.prompt % self.cfg.vocab_size
+                extra = modality_inputs(self.cfg, b, self._rng)
+                pe = extra.get("prefix_embeds")
+                tokens = jnp.asarray(tokens)
 
-        gen = np.concatenate([np.asarray(t) for t in out], axis=1)
-        return [Result(uid=r.uid, tokens=gen[i], prefill_s=t1 - t0,
-                       decode_s=t2 - t1, backend=self.name, batch_size=b)
-                for i, r in enumerate(requests)]
+            with TraceAnnotation("ecore.prefill"):
+                t0 = time.perf_counter()
+                logits, cache = self._prefill(self.params, tokens, pe)
+                next_tok = jnp.argmax(logits[:, -1:],
+                                      axis=-1).astype(jnp.int32)
+                jax.block_until_ready(next_tok)
+                t1 = time.perf_counter()
+
+            out = [next_tok]
+            for _ in range(max_new - 1):
+                with TraceAnnotation("ecore.decode_step"):
+                    logits, cache = self._decode(self.params, next_tok, cache)
+                    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out.append(next_tok)
+            with TraceAnnotation("ecore.decode_wait"):
+                jax.block_until_ready(next_tok)
+            t2 = time.perf_counter()
+
+            with TraceAnnotation("ecore.gather"):
+                gen = np.concatenate([np.asarray(t) for t in out], axis=1)
+                results = [Result(uid=r.uid, tokens=gen[i], prefill_s=t1 - t0,
+                                  decode_s=t2 - t1, backend=self.name,
+                                  batch_size=b)
+                           for i, r in enumerate(requests)]
+        self.decode_slots += b * max_new
+        self.tokens_asked += asked
+        return results
 
     def profile_row(self) -> Dict[str, object]:
         return {"kind": "llm", "model": self.name,
@@ -144,8 +180,8 @@ class DispatchQueue:
         self.calls = 0
         self.served = 0
         #: partial batches served because the deadline expired — via submit,
-        #: poll, OR the service's background flusher (which bumps it before
-        #: flushing), so the metric is path-independent
+        #: poll, OR the service's background flusher (each flushes with
+        #: trigger ``deadline``), so the metric is path-independent
         self.deadline_flushes = 0
 
     def _deadline_passed(self) -> bool:
@@ -169,31 +205,40 @@ class DispatchQueue:
             self._oldest = self._clock()
         self.pending.append(req)
         if len(self.pending) >= self.backend.max_batch:
-            return self.flush()
+            return self.flush("full")
         if self._deadline_passed():
-            self.deadline_flushes += 1
-            return self.flush()
+            return self.flush("deadline")
         return []
 
     def poll(self) -> List[Result]:
         """Serve the pending partial batch if it has waited past
         ``max_wait_ms``; [] otherwise.  No-op without a deadline."""
         if self.pending and self._deadline_passed():
-            self.deadline_flushes += 1
-            return self.flush()
+            return self.flush("deadline")
         return []
 
-    def flush(self) -> List[Result]:
+    def flush(self, trigger: str = "drain") -> List[Result]:
+        """Serve everything pending, one ``serve_batch`` per prompt length.
+        ``trigger`` says why (``full``, ``deadline`` or ``drain``): a
+        ``deadline`` flush counts in ``deadline_flushes``, and the
+        ``ecore.flush`` span carries it with ``waited_ms``, the oldest
+        request's wait from its submit to this flush."""
         if not self.pending:
             return []
         batch, self.pending = self.pending, []
+        waited_ms = (self._clock() - self._oldest) * 1e3
         self._oldest = None
+        if trigger == "deadline":
+            self.deadline_flushes += 1
         by_len: Dict[int, List[Request]] = {}
         for r in batch:
             by_len.setdefault(len(r.prompt), []).append(r)
         results: List[Result] = []
-        for _, group in sorted(by_len.items()):
-            self.calls += 1
-            self.served += len(group)
-            results += self.backend.serve_batch(group)
+        with TraceAnnotation("ecore.flush", trigger=trigger,
+                             requests=len(batch), sub_batches=len(by_len),
+                             waited_ms=waited_ms):
+            for _, group in sorted(by_len.items()):
+                self.calls += 1
+                self.served += len(group)
+                results += self.backend.serve_batch(group)
         return results

@@ -29,6 +29,13 @@ than deadlocking).
 ``serve_batch`` runs under the service lock, so decisions, flushes and
 observations are serialized — batching, not intra-service parallelism, is
 the throughput lever (matching the paper's one-batch-at-a-time Locust loop).
+
+Each stage is a ``jax.profiler.TraceAnnotation`` span: ``ecore.submit``
+(metadata ``uid`` or ``requests``) around ``ecore.route``, the queue's
+``ecore.flush`` and the engine's spans inside it, ``ecore.complete`` while
+futures resolve, and ``ecore.hold`` while the flusher waits on a pending
+deadline.  A running profiler records them; otherwise they cost under a
+microsecond each.
 """
 from __future__ import annotations
 
@@ -38,6 +45,8 @@ import threading
 import time
 from concurrent.futures import Future
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.policy import (Observation, RouteDecision, RouteRequest,
                                RoutingPolicy)
@@ -92,20 +101,10 @@ class EcoreService:
         #: one queue per ROUTED PAIR — the same model on two devices/meshes
         #: must not collapse onto one backend
         self._queues: Dict[Tuple[str, str], DispatchQueue] = {}
-        #: uid -> (request, decision, future, submit_time, queue key)
+        #: uid -> (request, decision, future, queue key)
         self._inflight: Dict[int, Tuple[RouteRequest, RouteDecision,
-                                        Future, float, Tuple[str, str]]] = {}
+                                        Future, Tuple[str, str]]] = {}
         self._completed: List[Served] = []
-        # bounded: a long-lived service must not grow per-request state.
-        # Two separate planes per request: queue_wait (submit -> its flush
-        # TRIGGERED: deadline expiry / batch full / drain — bounded by
-        # max_wait_ms under a healthy flusher) and service (trigger ->
-        # completion: lock wait behind other serves + the serve itself).
-        # Folding the second into the first made p95 "queue wait" report
-        # seconds of jit-compile head-of-line blocking against a 25 ms
-        # deadline.
-        self._queue_wait_ms: Deque[float] = collections.deque(maxlen=4096)
-        self._service_ms: Deque[float] = collections.deque(maxlen=4096)
         # backend errors caught in the flusher thread: futures carry them,
         # but results()-driven drivers never look — re-raised at
         # drain()/close() so a lost batch cannot pass silently
@@ -129,9 +128,11 @@ class EcoreService:
         """Route one request and enqueue it on its backend's dispatch queue.
         The returned future resolves to a ``Served`` when the batch flushes
         (full batch, deadline expiry, ``drain`` or ``close``)."""
-        with self._cond:
+        with TraceAnnotation("ecore.submit", uid=req.uid), self._cond:
             self._ensure_open()
-            fut = self._enqueue(req, self.policy.decide(req))
+            with TraceAnnotation("ecore.route"):
+                decision = self.policy.decide(req)
+            fut = self._enqueue(req, decision)
             self._cond.notify_all()   # new deadline for the flusher
             return fut
 
@@ -147,10 +148,11 @@ class EcoreService:
         (``DetectionPolicy.decide_scan``), so the service must dispatch
         exactly those decisions rather than re-deciding against the
         already-updated profile."""
-        with self._cond:
+        with TraceAnnotation("ecore.submit", requests=len(reqs)), self._cond:
             self._ensure_open()
             if decisions is None:
-                decisions = self.policy.decide_batch(list(reqs))
+                with TraceAnnotation("ecore.route"):
+                    decisions = self.policy.decide_batch(list(reqs))
             elif len(decisions) != len(reqs):
                 raise ValueError(
                     f"{len(decisions)} decisions for {len(reqs)} requests")
@@ -203,7 +205,7 @@ class EcoreService:
             # the flush resolved or failed every normal future; whatever is
             # STILL pending (a backend that returned a partial batch, a
             # cancelled flush) must not dangle past close
-            for uid, (_, _, fut, _, _) in list(self._inflight.items()):
+            for uid, (_, _, fut, _) in list(self._inflight.items()):
                 del self._inflight[uid]
                 fut.set_exception(ServiceClosed(
                     f"EcoreService closed with request uid {uid} unserved"))
@@ -261,14 +263,22 @@ class EcoreService:
         return sum(q.deadline_flushes for q in self._queues.values())
 
     def stats(self) -> Dict:
+        """Counters summed over the queues.  ``decode_slots`` and
+        ``tokens_asked`` are the LLM engine's (``engine.Backend``; 0 for
+        other backends), each backend counted once however many routed
+        pairs share it."""
         with self._cond:
+            backends = {id(q.backend): q.backend
+                        for q in self._queues.values()}.values()
             return {
                 "backends": len(self._queues),
                 "serve_calls": sum(q.calls for q in self._queues.values()),
                 "served": sum(q.served for q in self._queues.values()),
                 "deadline_flushes": self.deadline_flushes,
-                "queue_wait_ms": list(self._queue_wait_ms),
-                "service_ms": list(self._service_ms),
+                "decode_slots": sum(getattr(b, "decode_slots", 0)
+                                    for b in backends),
+                "tokens_asked": sum(getattr(b, "tokens_asked", 0)
+                                    for b in backends),
             }
 
     # ----------------------------------------------------------- internals
@@ -289,58 +299,45 @@ class EcoreService:
                               clock=self._clock)
             self._queues[key] = q
         fut: "Future[Served]" = Future()
-        self._inflight[req.uid] = (req, decision, fut, self._clock(), key)
-        self._dispatch(key, q, lambda: q.submit(
-            Request(uid=req.uid, prompt=req.payload,
-                    max_new_tokens=req.max_new_tokens,
-                    group=decision.group)))
+        self._inflight[req.uid] = (req, decision, fut, key)
+        self._dispatch(key, q.submit,
+                       Request(uid=req.uid, prompt=req.payload,
+                               max_new_tokens=req.max_new_tokens,
+                               group=decision.group))
         return fut
 
-    def _dispatch(self, key: Tuple[str, str], q: DispatchQueue, fn,
-                  t_trigger: Optional[float] = None) -> None:
-        """Run one queue operation that may serve a batch.  ``t_trigger``
-        is the moment the flush became DUE (deadline expiry, drain entry;
-        defaults to now for inline full-batch flushes) — queue wait ends
-        there, everything after is service time.  A backend error must not
-        kill the flusher thread or dangle futures: every inflight future of
-        the failing backend gets the exception (the flushed batch was
-        already popped, and any same-flush sub-batch results are lost with
-        it), then the error propagates to a direct caller."""
-        if t_trigger is None:
-            t_trigger = self._clock()
+    def _dispatch(self, key: Tuple[str, str], fn, arg) -> None:
+        """Run one queue operation, ``fn(arg)``, that may serve a batch.  A
+        backend error must not kill the flusher thread or dangle futures:
+        every inflight future of the failing backend gets the exception
+        (the flushed batch was already popped, and any same-flush
+        sub-batch results are lost with it), then the error propagates to
+        a direct caller."""
         try:
-            self._complete(fn(), t_trigger)
+            self._complete(fn(arg))
         except Exception as exc:
-            for uid, (_, _, fut, _, k) in list(self._inflight.items()):
+            for uid, (_, _, fut, k) in list(self._inflight.items()):
                 if k == key:
                     del self._inflight[uid]
                     fut.set_exception(exc)
             raise
 
-    def _complete(self, results: List[Result],
-                  t_trigger: Optional[float] = None) -> None:
-        t_done = self._clock()
-        if t_trigger is None:
-            t_trigger = t_done
-        for res in results:
-            req, decision, fut, t_submit, _ = self._inflight.pop(res.uid)
-            # time spent QUEUED for batching vs time being SERVED (incl.
-            # waiting behind other flushes under the service lock)
-            self._queue_wait_ms.append(max(t_trigger - t_submit, 0.0) * 1e3)
-            self._service_ms.append((t_done - t_trigger) * 1e3)
-            served = Served(request=req, decision=decision, result=res)
-            if self._retain:
-                self._completed.append(served)
-            fut.set_result(served)
+    def _complete(self, results: List[Result]) -> None:
+        if not results:
+            return
+        with TraceAnnotation("ecore.complete"):
+            for res in results:
+                req, decision, fut, _ = self._inflight.pop(res.uid)
+                served = Served(request=req, decision=decision, result=res)
+                if self._retain:
+                    self._completed.append(served)
+                fut.set_result(served)
 
     def _flush_all(self) -> None:
         first_exc = None
-        # one trigger stamp for the whole drain: queues flushed later must
-        # not book earlier queues' serve time as their own queue wait
-        t_trigger = self._clock()
         for key, q in self._queues.items():
             try:
-                self._dispatch(key, q, q.flush, t_trigger=t_trigger)
+                self._dispatch(key, q.flush, "drain")
             except Exception as exc:  # futures already carry it; drain the
                 first_exc = first_exc or exc        # healthy queues anyway
         if first_exc is not None:
@@ -358,7 +355,9 @@ class EcoreService:
                     continue
                 wait_s = min(deadlines) - self._clock()
                 if wait_s > 0:
-                    self._cond.wait(min(wait_s, self.FLUSH_TICK_S))
+                    # a partial batch is held for its deadline
+                    with TraceAnnotation("ecore.hold"):
+                        self._cond.wait(min(wait_s, self.FLUSH_TICK_S))
                     continue
                 self._flush_due_locked(self._clock())
 
@@ -369,12 +368,9 @@ class EcoreService:
         for key, q in list(self._queues.items()):
             nd = q.next_deadline()
             if nd is not None and nd <= now:
-                q.deadline_flushes += 1
                 flushed += 1
                 try:
-                    # wait ended when the deadline EXPIRED, not when
-                    # the flush got the lock
-                    self._dispatch(key, q, q.flush, t_trigger=nd)
+                    self._dispatch(key, q.flush, "deadline")
                 except Exception as exc:
                     # futures carry the backend error and drain()/
                     # close() re-raise it; flushing must survive
