@@ -257,7 +257,8 @@ def llm_phase(seed: int) -> None:
 
 
 def llm_ref_phase(seed: int) -> None:
-    from repro.models import decode_step, forward, prefill
+    from repro.models import forward
+    from repro.serving.engine import step_programs
 
     cfg = dataclasses.replace(full_config(), num_layers=REF_LAYERS)
     ref_cfg = dataclasses.replace(cfg, param_dtype="float32",
@@ -267,9 +268,7 @@ def llm_ref_phase(seed: int) -> None:
     tokens, backends = serve_llm(cfg, reqs, seed=seed, max_batch=4)
     params = backends[0].params
     params32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-    run_prefill = jax.jit(
-        lambda p, t: prefill(p, cfg, t, None, max_seq=MAX_SEQ))
-    run_decode = jax.jit(lambda p, t, c: decode_step(p, cfg, t, c))
+    run_prefill, run_decode = step_programs(cfg, MAX_SEQ)
     run_ref = jax.jit(lambda p, t: forward(p, ref_cfg, t))
 
     worst = 0.0
@@ -279,7 +278,7 @@ def llm_ref_phase(seed: int) -> None:
         served = np.stack([tokens[r.uid] for r in group])  # [B, new]
         # the served logits, recomputed by the same steps the backend
         # runs, fed the served tokens
-        logits, cache = run_prefill(params, jnp.asarray(prompt))
+        logits, cache = run_prefill(params, jnp.asarray(prompt), None)
         steps = [logits[:, -1]]
         for k in range(MAX_NEW_TOKENS - 1):
             logits, cache = run_decode(params,

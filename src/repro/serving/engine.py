@@ -56,6 +56,27 @@ class Result:
     energy_mwh: Optional[float] = None
 
 
+def step_programs(cfg: ModelConfig, max_seq: int):
+    """The jitted ``(prefill, decode)`` programs a ``Backend`` serves.
+
+    ``prefill(params, tokens, prefix_embeds)`` returns the last position's
+    logits and a cache of ``max_seq`` slots; ``decode(params, token,
+    cache)`` returns the next logits and the cache advanced by one token.
+    ``decode`` takes its cache as a donated argument: it writes the new
+    token's columns into that buffer and returns it as the next cache, so
+    no step copies the whole cache, and the cache passed in is deleted.  A
+    caller rebinds its cache to each step's output and never reads one it
+    has passed on."""
+    # named, so the device trace's program names say prefill or decode
+    def prefill_step(p, t, pe):
+        return prefill(p, cfg, t, pe, max_seq=max_seq)
+
+    def decode_token(p, t, c):
+        return decode_step(p, cfg, t, c)
+
+    return jax.jit(prefill_step), jax.jit(decode_token, donate_argnums=(2,))
+
+
 class Backend:
     """One (model x placement) pair exposing an inference API.
 
@@ -75,15 +96,7 @@ class Backend:
         self.max_batch = max_batch
         self.max_seq = max_seq
 
-        # named, so the device trace's program names say prefill or decode
-        def prefill_step(p, t, pe):
-            return prefill(p, cfg, t, pe, max_seq=max_seq)
-
-        def decode_token(p, t, c):
-            return decode_step(p, cfg, t, c)
-
-        self._prefill = jax.jit(prefill_step)
-        self._decode = jax.jit(decode_token)
+        self._prefill, self._decode = step_programs(cfg, max_seq)
         self._rng = np.random.default_rng(seed)
         #: batch x its largest max_new_tokens, summed over serve_batch calls
         #: (the decode slots run), and each request's own max_new_tokens

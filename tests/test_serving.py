@@ -1,9 +1,13 @@
 """Serving engine + TPU pool routing tests."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
 from repro.core.profiles import ProfileEntry, ProfileTable
+from repro.data.tokens import modality_inputs
+from repro.models import decode_step
 from repro.serving.engine import Backend, Request
 from repro.serving.pool import (LENGTH_BUCKETS, ServingPool, bucket_of,
                                 capability_score)
@@ -60,11 +64,42 @@ def test_backend_stateful_families():
     assert res.tokens.shape == (4,)
 
 
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "mamba2-370m",
+                                  "recurrentgemma-2b", "whisper-small"])
+def test_decode_step_consumes_its_cache(arch):
+    """The served decode step takes its cache as a donated buffer, for
+    the dense, SSM, hybrid and encoder-decoder families: the cache passed
+    in is deleted, and ``serve_batch``'s greedy tokens are those of the
+    same step jitted without donation."""
+    cfg = get_config(arch).reduced()
+    be = Backend(arch, cfg, max_seq=32, seed=3)
+    reqs = [Request(uid=i, prompt=np.arange(6) + 7 * i, max_new_tokens=5)
+            for i in range(2)]
+    served = np.stack([r.tokens for r in be.serve_batch(reqs)])
+
+    # the same prefill, modality inputs (the backend's first draw) and
+    # greedy loop, through an undonated decode step
+    tokens = jnp.asarray(np.stack([r.prompt for r in reqs]), jnp.int32)
+    pe = modality_inputs(cfg, len(reqs),
+                         np.random.default_rng(3)).get("prefix_embeds")
+    undonated = jax.jit(lambda p, t, c: decode_step(p, cfg, t, c))
+    logits, cache = be._prefill(be.params, tokens, pe)
+    tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+    want = [tok]
+    for _ in range(4):
+        logits, cache = undonated(be.params, tok, cache)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        want.append(tok)
+    np.testing.assert_array_equal(served, np.concatenate(want, axis=1))
+
+    passed = jax.tree.leaves(cache)
+    be._decode(be.params, tok, cache)
+    assert passed and all(x.is_deleted() for x in passed)
+
+
 def test_backend_commits_weights_to_the_default_device():
     """A backend commits its weights to the device that is the default
     when it is built, so its steps run there from any thread."""
-    import jax
-
     cfg = get_config("qwen2.5-3b").reduced()
     dev = jax.devices()[-1]
     with jax.default_device(dev):

@@ -12,6 +12,7 @@ must all collect the same tests.  Where it cannot be described the tests
 skip.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.canny_fused.canny_fused import (
     VMEM_BUDGET_BYTES, canny_edge_pallas, pick_tiles, tile_bytes)
-from repro.models import decode_step, init_params, prefill
+from repro.models import init_params
+from repro.serving.engine import step_programs
 
 #: v5e HBM per chip, rounded down
 HBM_BYTES = 16 * 10 ** 9
@@ -82,16 +84,38 @@ def qwen(one_chip):
     return cfg, params
 
 
+def _entry_copies_of(hlo_text, params):
+    """The copies (``copy``, ``copy-start``) in the entry computation
+    whose operand is one of the entry parameters numbered ``params``."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    names = {m[1] for m in re.finditer(r"%(\S+) = \S+ parameter\((\d+)\)",
+                                       entry) if int(m[2]) in params}
+    return [m[0] for m in re.finditer(r"%\S+ = .*? copy(?:-start)?\(%([^,)]+)\)",
+                                      entry) if m[1] in names]
+
+
 def test_qwen_prefill_and_decode_compile_and_fit_one_chip(one_chip, qwen):
     """The serving steps chip_smoke.py runs (batch 4, 512-token prompts,
-    a 1024-token cache) compile for one v5e and fit its HBM."""
+    a 1024-token cache) compile for one v5e and fit its HBM, and the
+    decode step writes into the cache it is given: the whole cache is
+    aliased to the output, and no copy of it is made on entry."""
     cfg, params = qwen
     b, s, max_seq = 4, 512, 1024
+    step, decode = step_programs(cfg, max_seq)
     tokens = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
-    step = jax.jit(lambda p, t: prefill(p, cfg, t, None, max_seq=max_seq))
-    m = _fits_hbm(step.lower(params, tokens).compile())
+    m = _fits_hbm(step.lower(params, tokens, None).compile())
     assert m.argument_size_in_bytes > 6e9     # all 36 layers, in bf16
-    _, cache = jax.eval_shape(step, params, tokens)
+    _, cache = jax.eval_shape(step, params, tokens, None)
     token = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
-    decode = jax.jit(lambda p, t, c: decode_step(p, cfg, t, c))
-    _fits_hbm(decode.lower(params, token, _on(one_chip, cache)).compile())
+    compiled = decode.lower(params, token, _on(one_chip, cache)).compile()
+    m = _fits_hbm(compiled)
+    cache_leaves = jax.tree.leaves(cache)
+    assert m.alias_size_in_bytes >= sum(x.size * x.dtype.itemsize
+                                        for x in cache_leaves)
+    # entry parameters: the weights, the token, then the cache's leaves;
+    # the scalar position may still be copied into scalar memory
+    first = len(jax.tree.leaves(params)) + 1
+    arrays = {first + i for i, x in enumerate(cache_leaves) if x.ndim}
+    assert len(arrays) == 3                   # k, v, pos_buf
+    assert _entry_copies_of(compiled.as_text(), arrays) == []
